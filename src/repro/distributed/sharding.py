@@ -33,38 +33,11 @@ __all__ = ["shard", "shard_map", "logical_to_spec", "current_mesh",
 
 
 def shard_map(body, *, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: new jax exposes it top-level
-    with check_vma=, older jax only has jax.experimental.shard_map with
-    check_rep= (replication checking is disabled either way — bodies
-    here use psum/ppermute explicitly)."""
-    if hasattr(jax, "shard_map"):
-        import inspect
-        params = inspect.signature(jax.shard_map).parameters
-        flag = {"check_vma": False} if "check_vma" in params \
-            else {"check_rep": False}
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **flag)
-    # Old jax cannot detect the manual context from the mesh, so flag it
-    # ourselves while the body traces and `shard()` becomes a no-op (the
-    # body is already per-device; old check_rep also has no rep rule for
-    # sharding_constraint). check_rep stays False: the rep checker
-    # predates device-varying cond branches (axis_index-gated compute).
-    from jax.experimental.shard_map import shard_map as _shard_map
+    """``jax.shard_map`` with replication checking off (bodies here use
+    psum/ppermute explicitly)."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-    def wrapped(*a, **kw):
-        global _OLD_SHARD_MAP_TRACING
-        prev = _OLD_SHARD_MAP_TRACING
-        _OLD_SHARD_MAP_TRACING = True
-        try:
-            return body(*a, **kw)
-        finally:
-            _OLD_SHARD_MAP_TRACING = prev
-
-    return _shard_map(wrapped, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
-_OLD_SHARD_MAP_TRACING = False
 
 _MAPPING = "tp"      # module-level; set during tracing via logical_mapping
 
@@ -88,7 +61,6 @@ def current_mapping() -> str:
 
 
 def current_mesh() -> Optional[Mesh]:
-    m = jax.sharding.get_abstract_mesh() if hasattr(jax.sharding, "get_abstract_mesh") else None
     try:
         from jax._src import mesh as mesh_lib
         env = mesh_lib.thread_resources.env
@@ -136,14 +108,8 @@ def logical_to_spec(mesh: Mesh, axes: Sequence[Optional[str]]) -> P:
 def _in_manual_context() -> bool:
     """True while tracing inside shard_map (Manual mesh axes) — sharding
     constraints are invalid there; the body is already per-device."""
-    if _OLD_SHARD_MAP_TRACING:
-        return True
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        return am is not None and any(
-            t == jax.sharding.AxisType.Manual for t in am.axis_types)
-    except Exception:
-        return False
+    return any(t == jax.sharding.AxisType.Manual
+               for t in jax.sharding.get_abstract_mesh().axis_types)
 
 
 def shard(x, *axes: Optional[str]):

@@ -14,7 +14,11 @@ codebook row, while the OUTPUT block covers ``rows_per_step`` rows and is
 revisited for every (row, h) step of its tile (Pallas keeps revisited
 blocks resident), so each output tile is written back to HBM exactly once.
 The embedding dim is the lane dimension (pad to 128 for peak DMA
-efficiency; any d is accepted).
+efficiency; any d is accepted). The codebook is passed as a [K, 1, d]
+view so that a one-row block spans the array's last two dims, and the
+sketch indices are prefetched flat (1-D SMEM pads nothing to 128 lanes);
+a batch whose index exceeds ``MAX_PREFETCH`` entries is split into
+several calls.
 
 ``binary=True`` applies the paper's binary-Y rule in-kernel: a duplicate
 sketch index (e.g. SCU falling back to the primary cluster) contributes
@@ -32,7 +36,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .platform import resolve_interpret
 
-__all__ = ["codebook_lookup_pallas"]
+__all__ = ["codebook_lookup_pallas", "MAX_PREFETCH"]
+
+# Scalar-prefetched indices live in SMEM (1 MiB on TPU v5e). A 1-D int32
+# operand of this many entries takes 512 KiB; a batch whose flattened
+# index is longer is split into several kernel calls.
+MAX_PREFETCH = 1 << 17
 
 
 def _kernel(idx_ref, row_ref, out_ref, *, n_hot: int, rows_per_step: int,
@@ -48,10 +57,10 @@ def _kernel(idx_ref, row_ref, out_ref, *, n_hot: int, rows_per_step: int,
 
     contrib = row_ref[0, :].astype(out_ref.dtype)
     if binary and n_hot > 1:
-        cur = idx_ref[row, h]
+        cur = idx_ref[row * n_hot + h]
         dup = jnp.zeros((), jnp.bool_)
         for j in range(n_hot - 1):        # j < h <= n_hot-1
-            dup = dup | ((j < h) & (idx_ref[row, j] == cur))
+            dup = dup | ((j < h) & (idx_ref[row * n_hot + j] == cur))
         contrib = jnp.where(dup, jnp.zeros_like(contrib), contrib)
     out_ref[r, :] += contrib
 
@@ -64,11 +73,8 @@ def codebook_lookup_pallas(codebook, idx, *, binary: bool = False,
     so the DMA pipeline overlaps fetch (row i+1, h) with compute of row i;
     rows_per_step output rows share one VMEM-resident output block.
 
-    ``interpret=None`` resolves per call — compile on TPU, interpret
-    everywhere else, REPRO_PALLAS_INTERPRET overrides (the old signature
-    hardwired ``interpret=True``, silently interpreting on accelerators).
-    Resolution happens OUTSIDE the jitted impl so the env override is
-    honored even after the program cache is warm.
+    ``interpret=None`` compiles on TPU and interprets everywhere else
+    (``resolve_interpret``); resolution happens outside the jitted impl.
     """
     return _codebook_lookup_jit(codebook, idx, binary=binary,
                                 rows_per_step=rows_per_step,
@@ -80,26 +86,40 @@ def codebook_lookup_pallas(codebook, idx, *, binary: bool = False,
 def _codebook_lookup_jit(codebook, idx, *, binary: bool,
                          rows_per_step: int, interpret: bool):
     b, h = idx.shape
-    k, d = codebook.shape
     r = max(1, min(rows_per_step, b))
-    b_pad = ((b + r - 1) // r) * r
+    # rows per kernel call: a multiple of r whose flat index fits SMEM
+    chunk = max(r, (MAX_PREFETCH // h) // r * r)
+    outs = [_lookup_call(codebook, idx[lo:lo + chunk], binary=binary, r=r,
+                         interpret=interpret)
+            for lo in range(0, b, chunk)]
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+
+
+def _lookup_call(codebook, idx, *, binary: bool, r: int, interpret: bool):
+    b, h = idx.shape
+    k, d = codebook.shape
+    b_pad = -(-b // r) * r
     idx_padded = idx if b_pad == b else jnp.pad(idx, ((0, b_pad - b), (0, 0)))
 
+    # [K, 1, d] view: a (1, d) block then spans the array's last two dims,
+    # which Mosaic accepts for any K and d
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b_pad // r, r, h),
         in_specs=[
-            pl.BlockSpec((1, d), functools.partial(
-                lambda i, rr, hh, idx_ref, r_: (idx_ref[i * r_ + rr, hh], 0),
-                r_=r)),
+            pl.BlockSpec((None, 1, d), functools.partial(
+                lambda i, rr, hh, idx_ref, r_, h_:
+                (idx_ref[(i * r_ + rr) * h_ + hh], 0, 0), r_=r, h_=h)),
         ],
         out_specs=pl.BlockSpec((r, d), lambda i, rr, hh, idx_ref: (i, 0)),
     )
     fn = pl.pallas_call(
         functools.partial(_kernel, n_hot=h, rows_per_step=r, binary=binary),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b_pad, d), codebook.dtype),
+        # f32 accumulation: also keeps the per-row stores 32-bit, which
+        # Mosaic can place at any row of the block
+        out_shape=jax.ShapeDtypeStruct((b_pad, d), jnp.float32),
         interpret=interpret,
     )
-    out = fn(idx_padded, codebook)
-    return out if b_pad == b else out[:b]
+    out = fn(idx_padded.reshape(-1), codebook.reshape(k, 1, d))
+    return (out if b_pad == b else out[:b]).astype(codebook.dtype)

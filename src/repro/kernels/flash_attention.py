@@ -20,6 +20,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import resolve_interpret
+
 __all__ = ["flash_attention_pallas"]
 
 _NEG_INF = -1e30
@@ -73,7 +75,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                               "interpret"))
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret=None):
     """q/k/v [B, H, S, d] -> [B, H, S, d]."""
     b, h, sq, d = q.shape
     skv = k.shape[2]
@@ -100,6 +102,6 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
     return fn(qr, kr, vr).reshape(b, h, sq, d)

@@ -38,8 +38,8 @@ zero, and the mask add (+0.0) normalizes -0.0 away on the masked paths.
 
 Exclusion pairs ((row, item) scattered to -inf in-tile) use a jnp
 scatter, which Mosaic cannot lower — the exclusion path is
-interpret-mode only (eval uses it; serving masks via ``mask``, which
-compiles). ``kernels/ops.py`` routes around this automatically.
+interpret-mode only (serving masks via ``mask``, which compiles);
+``kernels/ops.py`` refuses exclusions on a compiled platform.
 """
 from __future__ import annotations
 
@@ -50,6 +50,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .codebook_lookup import MAX_PREFETCH
+from .platform import resolve_interpret
 
 __all__ = ["fused_topk_pallas", "fused_topk_codebook_pallas",
            "select_topk", "exclusion_tiles"]
@@ -164,7 +167,8 @@ def _full_mask(mask, n: int, pad: int):
 # ---------------------------------------------------------------------------
 # dense variant: explicit [N, d] item matrix
 # ---------------------------------------------------------------------------
-def _dense_kernel(*refs, k: int, tile: int, quantized: bool, excl: bool):
+def _dense_kernel(*refs, k: int, tile: int, b_block: int, quantized: bool,
+                  excl: bool):
     it = iter(refs)
     u_ref, v_ref = next(it), next(it)
     scale_ref = next(it) if quantized else None
@@ -173,21 +177,29 @@ def _dense_kernel(*refs, k: int, tile: int, quantized: bool, excl: bool):
     exc_ref = next(it) if excl else None
     vals_ref, ids_ref = next(it), next(it)
 
-    t = pl.program_id(0)
+    i = pl.program_id(0)
+    t = pl.program_id(1)
     v = v_ref[...]
     if quantized:
         v = v.astype(jnp.float32) * scale_ref[...]
     s = jnp.dot(u_ref[...], v.T, preferred_element_type=jnp.float32)
     s = s + mask_ref[0, :][None, :]
     if excl:
-        s = s.at[exr_ref[0], exc_ref[0]].set(_NEG_INF, mode="drop")
+        rows = exr_ref[0] - i * b_block          # block-local; others drop
+        rows = jnp.where((rows >= 0) & (rows < b_block), rows, b_block)
+        s = s.at[rows, exc_ref[0]].set(_NEG_INF, mode="drop")
     col = t * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     _merge_tile(s, col, vals_ref, ids_ref, k, t == 0)
 
 
+# User rows scored per grid step. The k selection rounds keep several
+# [rows, tile] temporaries in VMEM; 128 rows x 1024 items stays well
+# inside the 16 MiB scoped-VMEM limit of a TPU v5e.
+B_BLOCK = 128
+
+
 def fused_topk_pallas(u, items, k: int, *, scale=None, mask=None,
-                      exclude=None, block: int = 512,
-                      interpret: bool = True):
+                      exclude=None, block: int = 512, interpret=None):
     """``lax.top_k(u @ items.T + mask, k)`` without the score matrix.
 
     u [B, d] f32; items [N, d] f32, or int8 with ``scale`` f32 [N]
@@ -195,6 +207,8 @@ def fused_topk_pallas(u, items, k: int, *, scale=None, mask=None,
     (e.g. the capacity ladder's -inf pad mask); ``exclude`` is a host
     (rows, cols) pair scattered to -inf (interpret-mode only). Returns
     (values [B, k] f32, ids [B, k] int32) with lax.top_k tie-breaking.
+    Grid ``(B / B_BLOCK, N / tile)``: user rows beyond ``B_BLOCK`` are
+    scored in blocks of that many.
     """
     k = int(k)
     u = jnp.asarray(u, jnp.float32)
@@ -205,47 +219,52 @@ def fused_topk_pallas(u, items, k: int, *, scale=None, mask=None,
     v = jnp.asarray(items)
     if pad:
         v = jnp.concatenate([v, jnp.zeros((pad, d), v.dtype)])
+    bb = min(b, B_BLOCK)
+    b_pad = -(-b // bb) * bb
+    if b_pad != b:
+        u = jnp.concatenate([u, jnp.zeros((b_pad - b, d), u.dtype)])
     quantized = scale is not None
     excl = exclude is not None
 
-    in_specs = [pl.BlockSpec((b, d), lambda t: (0, 0)),
-                pl.BlockSpec((tile, d), lambda t: (t, 0))]
+    in_specs = [pl.BlockSpec((bb, d), lambda i, t: (i, 0)),
+                pl.BlockSpec((tile, d), lambda i, t: (t, 0))]
     args = [u, v]
     if quantized:
         sc = jnp.asarray(scale, jnp.float32).reshape(-1, 1)
         if pad:
             sc = jnp.concatenate([sc, jnp.zeros((pad, 1), jnp.float32)])
-        in_specs.append(pl.BlockSpec((tile, 1), lambda t: (t, 0)))
+        in_specs.append(pl.BlockSpec((tile, 1), lambda i, t: (t, 0)))
         args.append(sc)
-    in_specs.append(pl.BlockSpec((1, tile), lambda t: (0, t)))
+    in_specs.append(pl.BlockSpec((1, tile), lambda i, t: (0, t)))
     args.append(m)
     if excl:
-        ex_r, ex_c = exclusion_tiles(exclude, nb, tile, row_sentinel=b)
+        ex_r, ex_c = exclusion_tiles(exclude, nb, tile, row_sentinel=b_pad)
         e = ex_r.shape[1]
-        in_specs += [pl.BlockSpec((1, e), lambda t: (t, 0)),
-                     pl.BlockSpec((1, e), lambda t: (t, 0))]
+        in_specs += [pl.BlockSpec((1, e), lambda i, t: (t, 0)),
+                     pl.BlockSpec((1, e), lambda i, t: (t, 0))]
         args += [jnp.asarray(ex_r), jnp.asarray(ex_c)]
 
     fn = pl.pallas_call(
-        functools.partial(_dense_kernel, k=k, tile=tile,
+        functools.partial(_dense_kernel, k=k, tile=tile, b_block=bb,
                           quantized=quantized, excl=excl),
-        grid=(nb,),
+        grid=(b_pad // bb, nb),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((b, k), lambda t: (0, 0)),
-                   pl.BlockSpec((b, k), lambda t: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b, k), jnp.float32),
-                   jax.ShapeDtypeStruct((b, k), jnp.int32)],
-        interpret=interpret,
+        out_specs=[pl.BlockSpec((bb, k), lambda i, t: (i, 0)),
+                   pl.BlockSpec((bb, k), lambda i, t: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b_pad, k), jnp.float32),
+                   jax.ShapeDtypeStruct((b_pad, k), jnp.int32)],
+        interpret=resolve_interpret(interpret),
     )
     vals, ids = fn(*args)
-    return vals, ids
+    return vals[:b], ids[:b]
 
 
 # ---------------------------------------------------------------------------
 # codebook variant: items expanded through the sketch, in-kernel
 # ---------------------------------------------------------------------------
 def _codebook_kernel(sk_ref, *refs, k: int, tile: int, n_hot: int,
-                     quantized: bool, excl: bool):
+                     b_block: int, n_bblocks: int, quantized: bool,
+                     excl: bool):
     it = iter(refs)
     u_ref, row_ref = next(it), next(it)
     scale_ref = next(it) if quantized else None
@@ -263,10 +282,10 @@ def _codebook_kernel(sk_ref, *refs, k: int, tile: int, n_hot: int,
         contrib = contrib * scale_ref[0, 0]
     if n_hot > 1:            # binary-Y dedup via the prefetched scalars
         item = t * tile + j
-        cur = sk_ref[item, hh]
+        cur = sk_ref[item * n_hot + hh]
         dup = jnp.zeros((), jnp.bool_)
         for jj in range(n_hot - 1):          # jj < hh <= n_hot-1
-            dup = dup | ((jj < hh) & (sk_ref[item, jj] == cur))
+            dup = dup | ((jj < hh) & (sk_ref[item * n_hot + jj] == cur))
         contrib = jnp.where(dup, jnp.zeros_like(contrib), contrib)
 
     @pl.when(hh == 0)
@@ -277,21 +296,31 @@ def _codebook_kernel(sk_ref, *refs, k: int, tile: int, n_hot: int,
     def _():
         vtile_ref[j, :] = vtile_ref[j, :] + contrib
 
-    # tile fully expanded in VMEM scratch: score + merge, once per tile
+    # tile fully expanded in VMEM scratch: score + merge, once per tile,
+    # B_BLOCK user rows at a time
     @pl.when(jnp.logical_and(j == tile - 1, hh == n_hot - 1))
     def _():
-        s = jnp.dot(u_ref[...], vtile_ref[...].T,
-                    preferred_element_type=jnp.float32)
-        s = s + mask_ref[0, :][None, :]
-        if excl:
-            s = s.at[exr_ref[0], exc_ref[0]].set(_NEG_INF, mode="drop")
-        col = t * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _merge_tile(s, col, vals_ref, ids_ref, k, t == 0)
+        def score_rows(bi, carry):
+            r0 = pl.multiple_of(bi * b_block, b_block)
+            rows = pl.ds(r0, b_block)
+            s = jnp.dot(u_ref[rows, :], vtile_ref[...].T,
+                        preferred_element_type=jnp.float32)
+            s = s + mask_ref[0, :][None, :]
+            if excl:
+                r = exr_ref[0] - r0              # block-local; others drop
+                r = jnp.where((r >= 0) & (r < b_block), r, b_block)
+                s = s.at[r, exc_ref[0]].set(_NEG_INF, mode="drop")
+            col = t * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            _merge_tile(s, col, vals_ref.at[rows], ids_ref.at[rows], k,
+                        t == 0)
+            return carry
+
+        jax.lax.fori_loop(0, n_bblocks, score_rows, 0)
 
 
 def fused_topk_codebook_pallas(u, codebook, sketch, k: int, *, scale=None,
                                mask=None, exclude=None, block: int = 128,
-                               interpret: bool = True):
+                               interpret=None):
     """Fused codebook expansion -> score -> top-k.
 
     u [B, d] f32; codebook [K, d] f32 or int8 with ``scale`` f32 [K];
@@ -301,11 +330,49 @@ def fused_topk_codebook_pallas(u, codebook, sketch, k: int, *, scale=None,
     into VMEM scratch one codebook row per grid step (scalar-prefetched
     DMA, exactly the ``codebook_lookup`` pipeline) and scored in place.
     Same mask/exclude/tie-break contract as ``fused_topk_pallas``.
+
+    The flattened sketch is scalar-prefetched into SMEM, so an item
+    range whose sketch exceeds ``MAX_PREFETCH`` entries is scored in
+    several calls whose per-call top-k are merged with ``lax.top_k``
+    (earlier calls hold lower ids, so ties still break to the lower id).
     """
     k = int(k)
+    sketch = jnp.asarray(sketch, jnp.int32)
+    n, h = sketch.shape
+    tile = _tile_plan(n, k, int(block))[0]
+    chunk = max(tile, (MAX_PREFETCH // h) // tile * tile)
+    cuts = list(range(0, n, chunk)) + [n]
+    if len(cuts) > 2 and cuts[-1] - cuts[-2] < k:
+        del cuts[-2]             # a tail shorter than k joins the call before
+    parts = []
+    for lo, hi in zip(cuts[:-1], cuts[1:]):
+        excl = None
+        if exclude is not None:
+            rows = np.asarray(exclude[0], np.int32)
+            cols = np.asarray(exclude[1], np.int32)
+            sel = (cols >= lo) & (cols < hi)
+            excl = (rows[sel], cols[sel] - lo)
+        vals, ids = _codebook_call(
+            u, codebook, sketch[lo:hi], k, scale=scale,
+            mask=None if mask is None else jnp.asarray(mask)[lo:hi],
+            exclude=excl, block=block, interpret=interpret)
+        parts.append((vals, ids + lo))
+    if len(parts) == 1:
+        return parts[0]
+    vals = jnp.concatenate([p[0] for p in parts], axis=1)
+    ids = jnp.concatenate([p[1] for p in parts], axis=1)
+    vals, pos = jax.lax.top_k(vals, k)
+    return vals, jnp.take_along_axis(ids, pos, axis=1)
+
+
+def _codebook_call(u, codebook, sketch, k: int, *, scale, mask, exclude,
+                   block: int, interpret):
     u = jnp.asarray(u, jnp.float32)
     b, d = u.shape
-    sketch = jnp.asarray(sketch, jnp.int32)
+    bb = min(b, B_BLOCK)
+    b_pad = -(-b // bb) * bb
+    if b_pad != b:
+        u = jnp.concatenate([u, jnp.zeros((b_pad - b, d), u.dtype)])
     n, h = sketch.shape
     tile, nb, pad = _tile_plan(n, k, int(block))
     m = _full_mask(mask, n, pad)
@@ -314,23 +381,25 @@ def fused_topk_codebook_pallas(u, codebook, sketch, k: int, *, scale=None,
             [sketch, jnp.zeros((pad, h), jnp.int32)])
     quantized = scale is not None
     excl = exclude is not None
+    kc = codebook.shape[0]
 
+    def row_map(t, j, hh, sk, tile_=tile, h_=h):
+        return (sk[(t * tile_ + j) * h_ + hh], 0, 0)
+
+    # [K, 1, d] views: a (1, d) block spans the array's last two dims,
+    # which Mosaic accepts for any K and d
     in_specs = [
-        pl.BlockSpec((b, d), lambda t, j, hh, sk: (0, 0)),
-        pl.BlockSpec((1, d), functools.partial(
-            lambda t, j, hh, sk, tile_: (sk[t * tile_ + j, hh], 0),
-            tile_=tile)),
+        pl.BlockSpec((b_pad, d), lambda t, j, hh, sk: (0, 0)),
+        pl.BlockSpec((None, 1, d), row_map),
     ]
-    args = [codebook]
+    args = [jnp.asarray(codebook).reshape(kc, 1, d)]
     if quantized:
-        in_specs.append(pl.BlockSpec((1, 1), functools.partial(
-            lambda t, j, hh, sk, tile_: (sk[t * tile_ + j, hh], 0),
-            tile_=tile)))
-        args.append(jnp.asarray(scale, jnp.float32).reshape(-1, 1))
+        in_specs.append(pl.BlockSpec((None, 1, 1), row_map))
+        args.append(jnp.asarray(scale, jnp.float32).reshape(kc, 1, 1))
     in_specs.append(pl.BlockSpec((1, tile), lambda t, j, hh, sk: (0, t)))
     args.append(m)
     if excl:
-        ex_r, ex_c = exclusion_tiles(exclude, nb, tile, row_sentinel=b)
+        ex_r, ex_c = exclusion_tiles(exclude, nb, tile, row_sentinel=b_pad)
         e = ex_r.shape[1]
         in_specs += [pl.BlockSpec((1, e), lambda t, j, hh, sk: (t, 0)),
                      pl.BlockSpec((1, e), lambda t, j, hh, sk: (t, 0))]
@@ -340,17 +409,18 @@ def fused_topk_codebook_pallas(u, codebook, sketch, k: int, *, scale=None,
         num_scalar_prefetch=1,
         grid=(nb, tile, h),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((b, k), lambda t, j, hh, sk: (0, 0)),
-                   pl.BlockSpec((b, k), lambda t, j, hh, sk: (0, 0))],
+        out_specs=[pl.BlockSpec((b_pad, k), lambda t, j, hh, sk: (0, 0)),
+                   pl.BlockSpec((b_pad, k), lambda t, j, hh, sk: (0, 0))],
         scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)],
     )
     fn = pl.pallas_call(
         functools.partial(_codebook_kernel, k=k, tile=tile, n_hot=h,
+                          b_block=bb, n_bblocks=b_pad // bb,
                           quantized=quantized, excl=excl),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, k), jnp.float32),
-                   jax.ShapeDtypeStruct((b, k), jnp.int32)],
-        interpret=interpret,
+        out_shape=[jax.ShapeDtypeStruct((b_pad, k), jnp.float32),
+                   jax.ShapeDtypeStruct((b_pad, k), jnp.int32)],
+        interpret=resolve_interpret(interpret),
     )
-    vals, ids = fn(sketch, u, *args)
-    return vals, ids
+    vals, ids = fn(sketch.reshape(-1), u, *args)
+    return vals[:b], ids[:b]

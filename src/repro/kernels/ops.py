@@ -1,8 +1,8 @@
 """Pallas kernels: jit'd public wrappers + the "pallas" lookup backend.
 
-On this CPU container kernels always run in interpret mode (the TPU is
-the *target*); on a real TPU backend pass interpret=False (the default
-resolves by platform).
+Each wrapper's ``interpret=None`` resolves by platform
+(``platform.resolve_interpret``): Mosaic-compiled on TPU, Pallas
+interpret mode everywhere else (the CPU test runs).
 
 Importing this module registers the "pallas" backend into the
 EmbeddingEngine registry (repro.embedding.engine) — the engine defers
@@ -40,19 +40,17 @@ def embedding_bag(table, values, segment_ids, num_segments, *,
                   interpret=None):
     return embedding_bag_pallas(table, values, segment_ids,
                                 num_segments=num_segments,
-                                interpret=_interpret(interpret))
+                                interpret=interpret)
 
 
 def dot_interaction(x, *, block_b=128, interpret=None):
-    return dot_interaction_pallas(x, block_b=block_b,
-                                  interpret=_interpret(interpret))
+    return dot_interaction_pallas(x, block_b=block_b, interpret=interpret)
 
 
 def flash_attention(q, k, v, *, causal=True, block_q=128, block_k=128,
                     interpret=None):
     return flash_attention_pallas(q, k, v, causal=causal, block_q=block_q,
-                                  block_k=block_k,
-                                  interpret=_interpret(interpret))
+                                  block_k=block_k, interpret=interpret)
 
 
 def fused_topk(u, items, k, *, sketch=None, scale=None, mask=None,
@@ -60,15 +58,16 @@ def fused_topk(u, items, k, *, sketch=None, scale=None, mask=None,
     """The "pallas" fused scorer (see repro.embedding.fused_topk for the
     dispatching public entry). Serving-forward only — no VJP.
 
-    The exclusion scatter inside the kernel does not lower under Mosaic;
-    when exclusions are requested on a compiled platform the call falls
-    through to the jnp reference twin (eval-only path — serving excludes
-    nothing and masks via ``mask``, which compiles)."""
+    The exclusion scatter inside the kernel does not lower under Mosaic,
+    so exclusions on a compiled platform raise. Serving excludes nothing
+    and masks via ``mask``, which compiles; evaluation with exclusions
+    uses ``training.eval.topk_streaming``'s "block" backend."""
     interpret = _interpret(interpret)
     has_excl = exclude is not None and len(exclude[0]) > 0
     if has_excl and not interpret:
-        return ref.fused_topk(u, items, k, sketch=sketch, scale=scale,
-                              mask=mask, exclude=exclude)
+        raise NotImplementedError(
+            "the compiled fused scorer cannot apply exclusion pairs; use "
+            "topk_streaming(backend='block') or interpret mode")
     excl = exclude if has_excl else None
     if sketch is not None:
         return fused_topk_codebook_pallas(u, items, sketch, k, scale=scale,
@@ -120,8 +119,8 @@ def _codebook_sum_vjp(codebook, flat_idx, keep_flat, binary):
 
 
 class PallasBackend(LookupBackend):
-    """Fused TPU kernels; interpret-mode fallback off-TPU so the parity
-    tests (tests/test_engine.py) run on CPU. Forward runs the kernel;
+    """Fused TPU kernels; interpret mode off-TPU so the parity tests
+    (tests/test_engine.py) run on CPU. Forward runs the kernel;
     backward is a pure-jnp scatter-add via custom_vjp, so the backend is
     usable inside jax.grad (training through compressed tables)."""
     name = "pallas"

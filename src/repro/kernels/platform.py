@@ -1,32 +1,19 @@
 """Platform-aware interpret/compile selection for every Pallas kernel.
 
-On TPU the kernels compile through Mosaic; everywhere else (this CPU CI
-container, GPU) they run in Pallas interpret mode — a correctness
-fallback, not a perf path. Resolution order:
-
-    explicit kwarg  >  REPRO_PALLAS_INTERPRET env  >  platform default
-
-The env override exists so CI can force either mode without touching
-call sites (e.g. ``REPRO_PALLAS_INTERPRET=1`` to smoke the interpret
-path on an accelerator image).
+On TPU the kernels compile through Mosaic; on any other platform (CPU
+test runs, GPU) they run in Pallas interpret mode, a correctness path
+and not a performance one. An explicit ``interpret=`` kwarg wins over
+the platform default; tests use it to pin either mode.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
 __all__ = ["resolve_interpret"]
-
-_ENV = "REPRO_PALLAS_INTERPRET"
-_FALSY = ("0", "false", "False", "no", "off")
 
 
 def resolve_interpret(override=None) -> bool:
     """True -> run the kernel interpreted; False -> compile (Mosaic)."""
     if override is not None:
         return bool(override)
-    env = os.environ.get(_ENV)
-    if env is not None and env != "":
-        return env not in _FALSY
     return jax.default_backend() != "tpu"

@@ -22,6 +22,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
@@ -58,6 +60,7 @@ def main(argv=None):
     ap.add_argument("--swap-extra-steps", type=int, default=40)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # fail fast on typo'd names, before any training happens
     from repro.embedding import normalize_backend

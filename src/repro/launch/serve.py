@@ -25,6 +25,8 @@ import sys
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _get_artifact(args):
     from repro.serve import CompressedArtifact
@@ -129,6 +131,7 @@ def main(argv=None):
                     help="ClusterEngine solver for on-the-spot "
                          "compression (auto picks per platform)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     # validate against the live registries, not a hard-coded list: a
     # typo'd name must fail HERE with what actually exists, not after
     # minutes of clustering+training (the build_sketch re-raise pattern)

@@ -22,6 +22,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
@@ -50,6 +52,7 @@ def main(argv=None):
     ap.add_argument("--artifact", default=None,
                     help="publish the final artifact (and last delta) here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.core import ClusterEngine, normalize_solver
     from repro.data import drifting_coclusters

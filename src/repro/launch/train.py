@@ -23,6 +23,8 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def paper_pipeline(args):
     from repro.core import ClusterEngine, build_sketch, normalize_solver
@@ -127,6 +129,7 @@ def main(argv=None):
                          "lanes; identical selection to the sequential "
                          "walk)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.arch:
         if args.arch.startswith(("gemma", "qwen", "kimi", "dbrx")):
             args.shape = ("train_4k" if args.shape == "train_batch"

@@ -281,15 +281,28 @@ def test_swap_under_fused_scorer_adds_zero_compiles(trained):
 # platform/interpret resolution
 # ---------------------------------------------------------------------------
 def test_resolve_interpret_env_and_kwarg(monkeypatch):
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    """The platform decides by default, an explicit kwarg overrides it,
+    and no environment variable can force interpret mode on a TPU."""
     assert resolve_interpret(None) == (jax.default_backend() != "tpu")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert resolve_interpret(None) is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert resolve_interpret(None) is True
-    # explicit kwarg beats the env
+    for value in ("0", "1"):
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", value)
+        assert resolve_interpret(None) == (jax.default_backend() != "tpu")
     assert resolve_interpret(False) is False
     assert resolve_interpret(True) is True
+
+
+def test_compiled_scorer_refuses_exclusions():
+    """On a compiled platform the fused scorer raises on exclusions
+    instead of silently running the jnp reference."""
+    u = jnp.asarray(_rand((3, 8), seed=13))
+    v = jnp.asarray(_rand((40, 8), seed=14))
+    excl = (np.array([0, 2], np.int32), np.array([5, 7], np.int32))
+    with pytest.raises(NotImplementedError, match="exclusion"):
+        ops.fused_topk(u, v, 4, exclude=excl, interpret=False)
+    # no exclusions: the compiled path is not refused up front
+    empty = (np.zeros(0, np.int32), np.zeros(0, np.int32))
+    _assert_matches_ref(ops.fused_topk(u, v, 4, exclude=empty),
+                        ref.fused_topk(u, v, 4))
 
 
 # ---------------------------------------------------------------------------

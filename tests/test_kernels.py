@@ -54,6 +54,38 @@ def test_embedding_bag_empty_segments():
     assert_allclose(np.asarray(out[5]), 0.0)
 
 
+def test_prefetch_split_matches_reference(monkeypatch):
+    """Indices longer than MAX_PREFETCH (SMEM) are split over several
+    kernel calls; the result is the one-call result."""
+    import repro.kernels.codebook_lookup as cl
+    import repro.kernels.embedding_bag as eb
+    import repro.kernels.fused_topk as ft
+    for mod in (cl, eb, ft):
+        monkeypatch.setattr(mod, "MAX_PREFETCH", 16)
+    cb = jnp.asarray(RNG.standard_normal((20, 8)), jnp.float32)
+    idx = RNG.integers(0, 20, (37, 2)).astype(np.int32)
+    idx[::3, 1] = idx[::3, 0]                 # binary-Y duplicates
+    out = cl.codebook_lookup_pallas(cb, jnp.asarray(idx), binary=True)
+    assert_allclose(np.asarray(out),
+                    np.asarray(ref.codebook_lookup_dedup(cb, idx)),
+                    **_tol(jnp.float32))
+    vals = jnp.asarray(RNG.integers(0, 20, 40), jnp.int32)
+    segs = jnp.asarray(np.sort(RNG.integers(0, 9, 40)), jnp.int32)
+    assert_allclose(np.asarray(eb.embedding_bag_pallas(
+        cb, vals, segs, num_segments=9)),
+        np.asarray(ref.embedding_bag(cb, vals, segs, 9)),
+        **_tol(jnp.float32))
+    u = jnp.asarray(RNG.standard_normal((3, 8)), jnp.float32)
+    for n in (45, 43):                        # 43: a short tail call
+        sk = jnp.asarray(RNG.integers(0, 20, (n, 2)), jnp.int32)
+        got = ft.fused_topk_codebook_pallas(u, cb, sk, 5, block=8)
+        want = ref.fused_topk(u, cb, 5, sketch=sk)
+        np.testing.assert_array_equal(np.asarray(got[1]),
+                                      np.asarray(want[1]))
+        assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                        rtol=1e-5, atol=1e-6)
+
+
 @pytest.mark.parametrize("b,f,d,bt", [(8, 27, 128, 4), (16, 27, 128, 16),
                                       (4, 8, 32, 2), (8, 41, 16, 8)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -126,7 +126,8 @@ def test_moe_local_matches_gspmd_path():
     batch = {"tokens": jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8]], jnp.int32),
              "targets": jnp.asarray([[2, 3, 4, 5, 6, 7, 8, 9]], jnp.int32)}
     loss_plain = T.train_loss(params, batch, cfg)          # no mesh
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with mesh:
         loss_local = jax.jit(
             lambda p, b: T.train_loss(p, b, cfg))(params, batch)

@@ -27,6 +27,7 @@ from typing import Optional
 import numpy as np
 
 from repro.core.graph import BipartiteGraph
+from repro.obs.scopes import SAMPLE
 
 __all__ = ["BPRSampler", "DeviceBPRSampler", "make_sampler",
            "available_samplers", "device_sample_fn"]
@@ -89,15 +90,17 @@ def device_sample_fn(edge_u, edge_v, n_items: int, batch_size: int):
     n_edges = int(edge_u.shape[0])
 
     def sample(seed, step):
-        key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
-        ke, kn = jax.random.split(key)
-        e = jax.random.randint(ke, (batch_size,), 0, n_edges)
-        users = edge_u[e]
-        pos = edge_v[e]
-        r = jax.random.randint(kn, (batch_size,), 0, max(n_items - 1, 1))
-        neg = r + (r >= pos).astype(r.dtype)
-        return (users.astype(jnp.int32), pos.astype(jnp.int32),
-                neg.astype(jnp.int32))
+        with jax.named_scope(SAMPLE):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
+            ke, kn = jax.random.split(key)
+            e = jax.random.randint(ke, (batch_size,), 0, n_edges)
+            users = edge_u[e]
+            pos = edge_v[e]
+            r = jax.random.randint(kn, (batch_size,), 0,
+                                   max(n_items - 1, 1))
+            neg = r + (r >= pos).astype(r.dtype)
+            return (users.astype(jnp.int32), pos.astype(jnp.int32),
+                    neg.astype(jnp.int32))
 
     return sample
 

@@ -174,8 +174,8 @@ class ContinuousBatcher:
         if not live:
             return
         # requests whose trace was sampled get retroactive queue /
-        # batch / dispatch / device / respond spans committed below;
-        # with tracing off every r.span is the no-op NULL_SPAN
+        # batch / dispatch spans committed below; with tracing off every
+        # r.span is the no-op NULL_SPAN
         traced = [r for r in live
                   if r.span is not None and r.span.sampled]
         ids = np.concatenate([r.user_ids for r in live])
@@ -183,9 +183,8 @@ class ContinuousBatcher:
             t_dispatch = clock.now()
             try:
                 disp = self._registry.dispatcher(tenant)
-                t_dev0 = clock.now()
                 values, items = disp(ids)
-                t_dev1 = clock.now()
+                t_answered = clock.now()
             except Exception as exc:
                 self._tele.bump("errors", len(live))
                 for r in live:
@@ -208,20 +207,17 @@ class ContinuousBatcher:
                                    parent=r.span, n_requests=len(live),
                                    n_ids=int(ids.shape[0]),
                                    n_padded=n_padded)
-            disp_sp = tr.record_span("dispatch", t_dispatch, t_dev1,
-                                     parent=batch, tenant=tenant)
-            tr.record_span("device", t_dev0, t_dev1, parent=disp_sp)
+            # the dispatcher's whole call: host padding, the device
+            # program and the copy of its answers back to the host
+            tr.record_span("dispatch", t_dispatch, t_answered,
+                           parent=batch, tenant=tenant)
         offset = 0
         for r in live:
             self._tele.queue_delay.record((t_dispatch - r.t_submit) * 1e3)
-            t_r0 = clock.now()
             r.ticket.resolve((values[offset:offset + r.n],
                               items[offset:offset + r.n]))
-            t_r1 = clock.now()
-            self._tele.e2e.record((t_r1 - r.t_submit) * 1e3)
+            self._tele.e2e.record((clock.now() - r.t_submit) * 1e3)
             self._tele.bump("responses")
             if r.span is not None and r.span.sampled:
-                self._tracer.record_span("respond", t_r0, t_r1,
-                                         parent=r.span)
                 r.span.end(outcome="ok")
             offset += r.n

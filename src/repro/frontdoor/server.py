@@ -31,8 +31,8 @@ the capacity ladder.
 
 Tracing: ``submit`` opens a per-request root span ("request") with an
 "admit" child on the caller thread and hands the root to the batcher on
-the Request; the batcher attributes queue/batch/dispatch/device/respond
-time retroactively and closes the root (see ContinuousBatcher._flush).
+the Request; the batcher attributes queue/batch/dispatch time
+retroactively and closes the root (see ContinuousBatcher._flush).
 With the ambient tracer disabled — the default — every span call is the
 shared no-op NULL_SPAN.
 """

@@ -18,6 +18,7 @@ import numpy as np
 from repro.core.graph import BipartiteGraph
 from repro.core.sketch import Sketch
 from repro.embedding import EmbeddingEngine, EmbeddingSpec, init_codebook
+from repro.obs.scopes import LOOKUP, LOSS, PROPAGATE
 
 __all__ = ["LightGCNConfig", "from_sketch", "engines", "make_statics",
            "sorted_edge_statics", "init_params", "all_embeddings",
@@ -126,10 +127,13 @@ def _base_embeddings(params, statics, cfg: LightGCNConfig):
     """Materialize E0 = [Y_u Z_u ; Y_v Z_v] (or the full tables)."""
     if cfg.k_users is not None:
         u_eng, v_eng = engines(cfg)
-        u = u_eng.codebook_lookup(params["user_table"], statics["sketch_u"],
-                                  jnp.arange(cfg.n_users))
-        v = v_eng.codebook_lookup(params["item_table"], statics["sketch_v"],
-                                  jnp.arange(cfg.n_items))
+        with jax.named_scope(LOOKUP):
+            u = u_eng.codebook_lookup(params["user_table"],
+                                      statics["sketch_u"],
+                                      jnp.arange(cfg.n_users))
+            v = v_eng.codebook_lookup(params["item_table"],
+                                      statics["sketch_v"],
+                                      jnp.arange(cfg.n_items))
         return u, v
     return params["user_table"], params["item_table"]
 
@@ -175,8 +179,10 @@ def _make_propagate(statics):
 
     def bwd(_, g):
         gnu, gnv = g
-        d_cv = _segsum_sorted(gnu[eu_i] * w_i[:, None], iv)
-        d_cu = _segsum_sorted(gnv[ev_u] * w_u[:, None], iu)
+        # traced apart from the forward: its ops need the scope again
+        with jax.named_scope(PROPAGATE):
+            d_cv = _segsum_sorted(gnu[eu_i] * w_i[:, None], iv)
+            d_cu = _segsum_sorted(gnv[ev_u] * w_u[:, None], iu)
         return d_cu, d_cv
 
     prop.defvjp(fwd, bwd)
@@ -196,14 +202,15 @@ def all_embeddings(params, statics, cfg: LightGCNConfig):
                                 num_segments=cfg.n_users),
             jax.ops.segment_sum(cu[eu] * w[:, None], ev,
                                 num_segments=cfg.n_items))
-    acc_u, acc_v = u, v
-    cu, cv = u, v
-    for _ in range(cfg.n_layers):
-        cu, cv = prop(cu, cv)
-        acc_u = acc_u + cu
-        acc_v = acc_v + cv
-    k = cfg.n_layers + 1
-    return acc_u / k, acc_v / k
+    with jax.named_scope(PROPAGATE):
+        acc_u, acc_v = u, v
+        cu, cv = u, v
+        for _ in range(cfg.n_layers):
+            cu, cv = prop(cu, cv)
+            acc_u = acc_u + cu
+            acc_v = acc_v + cv
+        k = cfg.n_layers + 1
+        return acc_u / k, acc_v / k
 
 
 def bpr_loss_fn(params, statics, batch, cfg: LightGCNConfig):
@@ -216,15 +223,16 @@ def bpr_loss_fn(params, statics, batch, cfg: LightGCNConfig):
     u_all, v_all = all_embeddings(params, statics, cfg)
     u0, v0 = _base_embeddings(params, statics, cfg)
     d = cfg.dim
-    uu = jnp.concatenate([u_all, u0], axis=1)[batch["user"]]
-    pi = jnp.concatenate([v_all, v0], axis=1)[batch["pos"]]
-    ni = jnp.concatenate([v_all, v0], axis=1)[batch["neg"]]
-    pos = jnp.sum(uu[:, :d] * pi[:, :d], axis=-1)
-    neg = jnp.sum(uu[:, :d] * ni[:, :d], axis=-1)
-    loss = -jnp.mean(jax.nn.log_sigmoid(pos - neg))
-    reg = (jnp.sum(uu[:, d:] ** 2) + jnp.sum(pi[:, d:] ** 2)
-           + jnp.sum(ni[:, d:] ** 2)) / batch["user"].shape[0]
-    return loss + cfg.l2 * reg
+    with jax.named_scope(LOSS):
+        uu = jnp.concatenate([u_all, u0], axis=1)[batch["user"]]
+        pi = jnp.concatenate([v_all, v0], axis=1)[batch["pos"]]
+        ni = jnp.concatenate([v_all, v0], axis=1)[batch["neg"]]
+        pos = jnp.sum(uu[:, :d] * pi[:, :d], axis=-1)
+        neg = jnp.sum(uu[:, :d] * ni[:, :d], axis=-1)
+        loss = -jnp.mean(jax.nn.log_sigmoid(pos - neg))
+        reg = (jnp.sum(uu[:, d:] ** 2) + jnp.sum(pi[:, d:] ** 2)
+               + jnp.sum(ni[:, d:] ** 2)) / batch["user"].shape[0]
+        return loss + cfg.l2 * reg
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,7 @@ append-only line-record discipline).
 File layout — one JSON object per line:
 
   {"kind": "header", "schema": 1, "wall_t0": ..., "perf_t0": ...,
-   "dropped": N, "n_spans": N}
+   "anchor_ms": ..., "dropped": N, "n_spans": N}
   {"kind": "span", "trace": "t000001", "span": "s000001", "parent": "",
    "name": "request", "start_ms": 12.3, "dur_ms": 4.5,
    "wall_start": 1754650000.123, "thread": "MainThread", "attrs": {...}}
@@ -13,7 +13,10 @@ File layout — one JSON object per line:
 ``start_ms`` is milliseconds since the tracer's perf anchor (directly
 comparable across every span in the file); ``wall_start`` anchors the
 span to calendar time for correlation with external logs and
-``jax.profiler`` trace directories.
+``jax.profiler`` trace directories. ``anchor_ms`` is the tracer's last
+``Tracer.anchor()`` reading on the same scale (null if never taken): a
+span lies at ``anchor_ns + (start_ms - anchor_ms) * 1e6`` in a profile
+whose ``obs.anchor`` annotation starts at ``anchor_ns``.
 """
 from __future__ import annotations
 
@@ -63,6 +66,9 @@ def export_jsonl(tracer: Tracer, path: str,
             "kind": "header", "schema": SCHEMA_VERSION,
             "wall_t0": round(tracer.wall_t0, 6),
             "perf_t0": tracer.perf_t0,
+            "anchor_ms": (None if tracer.anchor_t is None else
+                          round((tracer.anchor_t - tracer.perf_t0) * 1e3,
+                                4)),
             "dropped": tracer.dropped, "n_spans": len(spans),
         }) + "\n")
         for sp in spans:

@@ -13,7 +13,7 @@ A :class:`Tracer` hands out :class:`Span` objects three ways:
     sections all nest automatically.
   * ``tracer.record_span(name, t0, t1, parent=...)`` — a
     **retroactive** span committed from timestamps measured elsewhere.
-    The batcher uses this to attribute queue/dispatch/device time to
+    The batcher uses this to attribute queue/batch/dispatch time to
     every request in a coalesced batch without entering live spans per
     request on the hot path.
 
@@ -28,6 +28,13 @@ When ``device_annotations`` is on and jax is *already imported*
 XLA flags before backend init), live spans also enter a
 ``jax.profiler.TraceAnnotation``, so host spans show up as named
 regions inside device profiles captured by ``BenchRun --profile``.
+
+Retroactive spans never enter an annotation, so a profile cannot show
+them. :meth:`Tracer.anchor` puts every span on the profile's clock
+instead: it reads ``clock.now()`` inside a ``TraceAnnotation`` named
+:data:`ANCHOR`, so that reading and the annotation's start in the
+profile name one instant, and a span time ``t`` lies at
+``anchor_ns + (t - tracer.anchor_t) * 1e9`` on the profile's timeline.
 """
 from __future__ import annotations
 
@@ -37,8 +44,10 @@ from typing import Dict, List, Optional
 
 from .clock import now, wall
 
-__all__ = ["Span", "Tracer", "NULL_SPAN", "get_tracer", "set_tracer",
-           "configure"]
+__all__ = ["Span", "Tracer", "NULL_SPAN", "ANCHOR", "get_tracer",
+           "set_tracer", "configure"]
+
+ANCHOR = "obs.anchor"   # the profiler annotation Tracer.anchor() enters
 
 
 class _NullSpan:
@@ -167,6 +176,7 @@ class Tracer:
         # perf/wall pair anchoring monotonic timestamps to calendar time
         self.perf_t0 = now()
         self.wall_t0 = wall()
+        self.anchor_t: Optional[float] = None   # see anchor()
 
     # -- ambient span stack (per thread) --------------------------------
     def _stack(self) -> List[Span]:
@@ -257,6 +267,26 @@ class Tracer:
         self._commit(sp)
         return sp
 
+    def anchor(self) -> float:
+        """Read ``clock.now()`` inside a ``jax.profiler.TraceAnnotation``
+        named :data:`ANCHOR`; keep the reading as ``self.anchor_t`` and
+        return it.
+
+        Call it while a profile is being captured: the annotation's start
+        on the profile's timeline and the returned reading are one
+        instant (to within the annotation's entry, microseconds), which
+        maps every span of this process, retroactive and cross-thread
+        ones included, onto the profile. Works whether or not tracing is
+        enabled; without jax imported there is no profile to align with
+        and only the reading is taken."""
+        ann = _trace_annotation()
+        if ann is None:
+            self.anchor_t = now()
+        else:
+            with ann(ANCHOR):
+                self.anchor_t = now()
+        return self.anchor_t
+
     # -- collection -----------------------------------------------------
     def _commit(self, span: Span) -> None:
         if not span.sampled:
@@ -285,13 +315,17 @@ class Tracer:
     def _annotation_cls(self):
         """jax.profiler.TraceAnnotation when the bridge is on and jax is
         already imported; never triggers a jax import itself."""
-        if not self.device_annotations:
-            return None
-        jax = sys.modules.get("jax")
-        if jax is None:
-            return None
-        prof = getattr(jax, "profiler", None)
-        return getattr(prof, "TraceAnnotation", None) if prof else None
+        return _trace_annotation() if self.device_annotations else None
+
+
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation if jax is already imported, else
+    None; never triggers a jax import itself."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    prof = getattr(jax, "profiler", None)
+    return getattr(prof, "TraceAnnotation", None) if prof else None
 
 
 # -- the ambient, process-wide tracer ------------------------------------

@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.core.graph import pad_rung as _cap_rung
 from repro.obs import clock
+from repro.obs.scopes import SCORE, TOPK
 from repro.obs.trace import get_tracer
 from repro.embedding import (dequantize_params, fused_topk,
                              normalize_backend, params_quantized)
@@ -244,18 +245,22 @@ class RecsysSession(Session):
                     params = dequantize_params(params)
                     u, v = L.eval_embeddings(params, statics, mcfg,
                                              user_ids)
-                    return fused_topk(u, v, self.k,
-                                      mask=statics.get("item_mask"),
-                                      block=self._fused_block)
+                    with jax.named_scope(SCORE):
+                        return fused_topk(u, v, self.k,
+                                          mask=statics.get("item_mask"),
+                                          block=self._fused_block)
             else:
                 def score_topk(params, statics, user_ids):
                     params = dequantize_params(params)
-                    scores = L.score_all_items(params, statics, mcfg,
-                                               user_ids)
-                    mask = statics.get("item_mask")
-                    if mask is not None:   # capacity pad items -> -inf
-                        scores = scores + mask[None, :]
-                    return jax.lax.top_k(scores, self.k)
+                    # lookup and propagate nest inside: innermost wins
+                    with jax.named_scope(SCORE):
+                        scores = L.score_all_items(params, statics, mcfg,
+                                                   user_ids)
+                        mask = statics.get("item_mask")
+                        if mask is not None:   # capacity pad items -> -inf
+                            scores = scores + mask[None, :]
+                    with jax.named_scope(TOPK):
+                        return jax.lax.top_k(scores, self.k)
 
             self._fn = jax.jit(score_topk)
         new_params = jax.device_put(jax.tree.map(jnp.asarray, params))

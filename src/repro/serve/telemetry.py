@@ -29,6 +29,7 @@ are unchanged.
 """
 from __future__ import annotations
 
+from repro.obs.compiles import COMPILES, watch_compiles
 from repro.obs.metrics import (CounterSet, LatencyRecorder,
                                MetricsRegistry)
 
@@ -92,7 +93,10 @@ class FrontdoorTelemetry:
     bucket ladder: fill ratio = real ids / padded ids, and per-bucket
     occupancy counts. Counters: requests, responses, batches, coalesced
     (requests that shared a batch with another), shed (admission
-    refused), timeouts (expired in queue), cache_hits, swaps, errors.
+    refused), timeouts (expired in queue), cache_hits, swaps, errors;
+    and, from ``repro.obs.compiles``, the process's jaxpr traces and XLA
+    programs built since this telemetry was made (traces, trace_us,
+    compiles, compile_us): a warm front door serves with none.
     """
 
     def __init__(self):
@@ -103,7 +107,8 @@ class FrontdoorTelemetry:
         self.counters = self.registry.counter_set(
             "frontdoor", ("requests", "responses", "batches", "coalesced",
                           "shed", "timeouts", "cache_hits", "swaps",
-                          "errors"))
+                          "errors") + tuple(COMPILES.keys()))
+        watch_compiles(sink=self.counters)
         # batch-fill ratio: running mean, not a per-batch list
         self._fill_sum = 0.0
         self._fill_n = 0

@@ -10,10 +10,13 @@ lists — robust to None/state-dict leaves that break nested tree.map.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.obs.scopes import OPTIMIZER
 
 __all__ = ["adamw", "adafactor", "sgd", "Optimizer", "global_norm"]
 
@@ -27,6 +30,16 @@ def global_norm(tree) -> jnp.ndarray:
     leaves = jax.tree.leaves(tree)
     return jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
                         for g in leaves))
+
+
+def _scoped(update):
+    """``update`` under the ``optimizer`` named scope (HLO metadata only),
+    so profiles attribute its ops to the optimizer."""
+    @functools.wraps(update)
+    def scoped(grads, state, params):
+        with jax.named_scope(OPTIMIZER):
+            return update(grads, state, params)
+    return scoped
 
 
 def _clip(grads, grad_clip):
@@ -48,7 +61,7 @@ def sgd(lr: float = 1e-2):
             params, grads)
         return new_p, {"step": state["step"] + 1}
 
-    return Optimizer(init, update)
+    return Optimizer(init, _scoped(update))
 
 
 def adamw(lr: float = 1e-3, b1: float = 0.9, b2: float = 0.999,
@@ -84,7 +97,7 @@ def adamw(lr: float = 1e-3, b1: float = 0.9, b2: float = 0.999,
                  "m": jax.tree.unflatten(treedef, new_m),
                  "v": jax.tree.unflatten(treedef, new_v)})
 
-    return Optimizer(init, update)
+    return Optimizer(init, _scoped(update))
 
 
 def adafactor(lr: float = 1e-2, decay: float = 0.8, eps: float = 1e-30,
@@ -134,4 +147,4 @@ def adafactor(lr: float = 1e-2, decay: float = 0.8, eps: float = 1e-30,
         return (jax.tree.unflatten(treedef, new_p),
                 {"step": step, "fac": new_fac})
 
-    return Optimizer(init, update)
+    return Optimizer(init, _scoped(update))

@@ -71,9 +71,8 @@ def sorted_edge_statics(edge_u, edge_v, edge_norm, n_users: int,
     Both segment orientations as SORTED runs: the user side uses the
     edge list as-is (edges arrive sorted by user), the item side a
     stable item-order permutation of it — plus both CSR indptrs. The
-    propagation then reduces each side with a prefix-scan + boundary
-    diff instead of scatter-adds (XLA:CPU lowers scatter to a serial
-    update loop; the scan is ~4x faster and dominates the train step).
+    propagation then reduces each side with a blocked prefix sum read
+    at the segment boundaries (`_segsum_sorted`), with no scatter.
     """
     edge_u = np.asarray(edge_u)
     edge_v = np.asarray(edge_v)
@@ -138,21 +137,87 @@ def _base_embeddings(params, statics, cfg: LightGCNConfig):
     return params["user_table"], params["item_table"]
 
 
-def _segsum_sorted(data, indptr):
-    """Segment sum of sorted-run rows: prefix scan + boundary diff.
-    data [E, d] grouped into len(indptr)-1 contiguous segments.
+# rows of the in-block prefix; one block when the graph has fewer edges
+EDGE_BLOCK = 256
 
-    Precision trade: each segment is a difference of two global-prefix
-    values, so absolute error scales with the running-sum magnitude
-    (~eps * |prefix|) instead of the segment. For zero-mean embedding
-    columns the prefix is a random walk (~sqrt(E) * scale), harmless at
-    the repo's dataset scales (pinned vs the scatter path in tests); at
-    1e8+ edges prefer rebasing the scan per chunk or an f32->f64 scan."""
-    if data.shape[0] == 0:
-        return jnp.zeros((indptr.shape[0] - 1, data.shape[1]), data.dtype)
-    c = jax.lax.associative_scan(jnp.add, data, axis=0)
-    c = jnp.concatenate([jnp.zeros((1, data.shape[1]), data.dtype), c])
-    return c[indptr[1:]] - c[indptr[:-1]]
+
+def _edge_block(n_edges: int) -> int:
+    return max(1, min(EDGE_BLOCK, n_edges))
+
+
+def _pad_edges(index, weight):
+    """Pad an edge orientation's gather index and weight to a whole
+    number of prefix blocks: pad edges read row 0 with weight 0 and lie
+    past every segment, so only these 1-D vectors are ever padded."""
+    n = index.shape[0]
+    pad = -n % _edge_block(n)
+    return jnp.pad(index, (0, pad)), jnp.pad(weight, (0, pad))
+
+
+def _block_prefix(x):
+    """Inclusive prefix along axis 1 of [n, B, d]: a [B, B] lower
+    triangle of ones on the MXU, at full float32 precision (the default
+    would round the rows to bfloat16)."""
+    b = x.shape[1]
+    tri = jnp.tril(jnp.ones((b, b), x.dtype))
+    return jnp.einsum("ij,njd->nid", tri, x,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _carry(blocks):
+    """[n, d] exclusive prefix of the totals of [n, B, d] in-block
+    prefixes: what precedes each block."""
+    totals = _prefix(blocks[:, -1], blocks.shape[1])
+    return jnp.pad(totals[:-1], ((1, 0), (0, 0)))
+
+
+def _prefix(x, b: int):
+    """Inclusive prefix along axis 0 of [n, d], in blocks of b rows."""
+    n, d = x.shape
+    if n <= b:
+        return _block_prefix(x[None])[0]
+    y = _block_prefix(jnp.pad(x, ((0, -n % b), (0, 0))).reshape(-1, b, d))
+    return (y + _carry(y)[:, None]).reshape(-1, d)[:n]
+
+
+def _segsum_sorted(data, indptr):
+    """Segment sum of sorted-run rows: blocked prefix + boundary diff.
+    data [E, d] grouped into len(indptr)-1 contiguous segments, E a
+    whole number of `_edge_block(E)` rows (`_pad_edges`).
+
+    An inclusive prefix inside each block of B rows, plus an exclusive
+    prefix of the E/B block totals (the carry), gives the global prefix
+    P; segment [s, e) is P[e-1] - P[s-1] (P[-1] = 0), read as the
+    in-block difference plus the carry difference, so P itself is never
+    written and a segment inside one block sees no carry at all.
+
+    Precision trade: a segment that crosses a block boundary is a
+    difference of two global-prefix values, so its absolute error scales
+    with the running-sum magnitude (~eps * |prefix|) instead of the
+    segment. For zero-mean embedding columns the prefix is a random walk
+    (~sqrt(E) * scale), harmless at the repo's dataset scales (pinned vs
+    the scatter path in tests); at 1e8+ edges prefer rebasing the carry
+    per chunk or an f32->f64 carry."""
+    e, d = data.shape
+    if e == 0:
+        return jnp.zeros((indptr.shape[0] - 1, d), data.dtype)
+    b = _edge_block(e)
+    if e % b:
+        raise ValueError(f"{e} edge rows are not a whole number of "
+                         f"{b}-row blocks; pad them with _pad_edges")
+    inblock = _block_prefix(data.reshape(e // b, b, d))
+    carry = _carry(inblock)
+    inblock = inblock.reshape(e, d)
+
+    def prefix_parts(rows):               # P[rows] as (in-block, carry)
+        keep = (rows >= 0)[:, None]
+        at = jnp.maximum(rows, 0)
+        return (jnp.where(keep, inblock[at], 0),
+                jnp.where(keep, carry[at // b], 0))
+
+    end_in, end_carry = prefix_parts(indptr[1:] - 1)
+    start_in, start_carry = prefix_parts(indptr[:-1] - 1)
+    return (end_in - start_in) + (end_carry - start_carry)
 
 
 def _make_propagate(statics):
@@ -163,8 +228,9 @@ def _make_propagate(statics):
     "sum over edges into user" is "sum over edges into item", which is
     again a sorted segment sum under the opposite orientation (autodiff
     would instead emit the gathers' scatter-add transpose)."""
-    ev_u, w_u = statics["edge_v"], statics["edge_norm"]
-    eu_i, w_i = statics["edge_u_byitem"], statics["edge_norm_byitem"]
+    ev_u, w_u = _pad_edges(statics["edge_v"], statics["edge_norm"])
+    eu_i, w_i = _pad_edges(statics["edge_u_byitem"],
+                           statics["edge_norm_byitem"])
     iu, iv = statics["indptr_u"], statics["indptr_v"]
 
     def impl(cu, cv):

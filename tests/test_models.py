@@ -75,6 +75,102 @@ def test_bpr_loss_decreases_on_easy_problem():
     assert float(loss(params)) < l0
 
 
+B = L.EDGE_BLOCK
+
+
+def _segment_counts(n_edges, rng):
+    """Edges per segment for 9 sorted segments: 0, 4 and 8 empty, segment
+    3 holds half the edges (several blocks once n_edges > 4 B)."""
+    counts = np.zeros(9, np.int64)
+    counts[3] = n_edges // 2
+    counts[[1, 2, 5, 6, 7]] = rng.multinomial(n_edges - counts[3],
+                                              np.full(5, 0.2))
+    return counts
+
+
+@pytest.mark.parametrize("n_edges", [0, 1, B - 1, B, B + 1, 3 * B + 5,
+                                     50_021])
+def test_segsum_sorted_matches_float64(n_edges):
+    rng = np.random.default_rng(n_edges)
+    counts = _segment_counts(n_edges, rng)
+    seg = np.repeat(np.arange(9), counts)
+    indptr = np.concatenate([[0], np.cumsum(counts)]).astype(np.int32)
+    table = rng.standard_normal((64, 8)).astype(np.float32)
+    idx = rng.integers(0, 64, n_edges).astype(np.int32)
+    w = rng.random(n_edges).astype(np.float32)
+    pi, pw = L._pad_edges(jnp.asarray(idx), jnp.asarray(w))
+    assert pi.shape[0] % L._edge_block(n_edges) == 0
+    got = L._segsum_sorted(jnp.asarray(table)[pi] * pw[:, None],
+                           jnp.asarray(indptr))
+    ref = np.zeros((9, 8))
+    np.add.at(ref, seg, table[idx].astype(np.float64) * w[:, None])
+    assert_allclose(np.asarray(got), ref, rtol=1e-5, atol=1e-4)
+    assert not np.asarray(got)[[0, 4, 8]].any()
+
+
+def random_graph(n_users=70, n_items=50, n_edges=4 * B + 9, seed=0):
+    rng = np.random.default_rng(seed)
+    pairs = rng.choice(n_users * n_items, n_edges, replace=False)
+    return BipartiteGraph.from_edges(n_users, n_items, pairs // n_items,
+                                     pairs % n_items)
+
+
+def test_propagation_grad_matches_scatter_fallback():
+    g = random_graph()
+    assert g.n_edges >= 3 * B
+    cfg = L.LightGCNConfig(g.n_users, g.n_items, dim=8, n_layers=3)
+    params = L.init_params(jax.random.PRNGKey(0), cfg)
+    statics = L.make_statics(g)
+    minimal = {k: statics[k] for k in ("edge_u", "edge_v", "edge_norm")}
+    rng = np.random.default_rng(1)
+    cu = jnp.asarray(rng.standard_normal((g.n_users, 8)), jnp.float32)
+    cv = jnp.asarray(rng.standard_normal((g.n_items, 8)), jnp.float32)
+
+    def scalar(p, s):
+        u, v = L.all_embeddings(p, s, cfg)
+        return jnp.sum(u * cu) + jnp.sum(v * cv)
+
+    got = jax.grad(scalar)(params, statics)
+    ref = jax.grad(scalar)(params, minimal)
+    for name in ("user_table", "item_table"):
+        assert_allclose(np.asarray(got[name]), np.asarray(ref[name]),
+                        rtol=1e-5, atol=1e-6)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_propagation_has_no_interleaved_scan():
+    """The forward and the custom-VJP backward hold no interior-padded
+    `pad` (the log-depth scan's interleave) and no `concatenate` of
+    E + 1 rows (a materialized zero-led prefix)."""
+    g = random_graph()
+    assert g.n_edges > B
+    cfg = L.LightGCNConfig(g.n_users, g.n_items, dim=8, n_layers=2)
+    params = L.init_params(jax.random.PRNGKey(0), cfg)
+    statics = L.make_statics(g)
+    scalar = lambda p: sum(jnp.sum(t) for t in
+                           L.all_embeddings(p, statics, cfg))
+    padded = -(-g.n_edges // B) * B
+    for fn in (scalar, jax.grad(scalar)):
+        eqns = list(_eqns(jax.make_jaxpr(fn)(params).jaxpr))
+        assert any(e.primitive.name == "dot_general" for e in eqns)
+        for e in eqns:
+            if e.primitive.name == "pad":
+                assert all(i == 0 for _, _, i in e.params["padding_config"])
+            if e.primitive.name == "concatenate":
+                assert e.outvars[0].aval.shape[0] not in (g.n_edges + 1,
+                                                          padded + 1)
+
+
 # ---------------------------------------------------------------------------
 # transformer: decode == full forward
 # ---------------------------------------------------------------------------
